@@ -199,21 +199,32 @@ class CompiledLUTNet:
         Ragged batches are padded up to the next ``block_b`` multiple and
         sliced back, so any batch in (0, block_b] reuses one trace — a
         steady-state serving loop performs zero re-traces after warmup.
+
+        Two spans time its host work: ``engine.h2d``, the put of the
+        codes (the host lays them out and starts the copy), and
+        ``engine.dispatch``, until the forward call returns.  Nothing
+        waits for the device: the caller keeps async dispatch.
         """
-        codes = jnp.asarray(codes, dtype=jnp.int32)
+        with obs.span("engine.h2d"):
+            # the tier hands over its batch already on the device, where
+            # jnp.asarray would cost tens of µs of host time for nothing
+            if not (isinstance(codes, jax.Array)
+                    and codes.dtype == jnp.int32):
+                codes = jnp.asarray(codes, dtype=jnp.int32)
         if codes.ndim != 2 or codes.shape[1] != self.n_in:
             raise ValueError(
                 f"expected (batch, {self.n_in}) codes, got {codes.shape}")
         batch = codes.shape[0]
         if batch == 0:
             return jnp.zeros((0, self.n_out), dtype=jnp.int32)
-        padded = -(-batch // self.block_b) * self.block_b
-        if padded != batch:
-            codes = jnp.concatenate(
-                [codes, jnp.zeros((padded - batch, self.n_in),
-                                  dtype=codes.dtype)], axis=0)
-        out = self._apply(codes)
-        return out[:batch] if padded != batch else out
+        with obs.span("engine.dispatch"):
+            padded = -(-batch // self.block_b) * self.block_b
+            if padded != batch:
+                codes = jnp.concatenate(
+                    [codes, jnp.zeros((padded - batch, self.n_in),
+                                      dtype=codes.dtype)], axis=0)
+            out = self._apply(codes)
+            return out[:batch] if padded != batch else out
 
     def _apply(self, codes: jax.Array) -> jax.Array:
         fn, arrays = self.forward_parts()

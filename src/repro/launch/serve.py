@@ -231,10 +231,11 @@ def _run_http(args: argparse.Namespace, net, bw, reference,
 def _run_lut(args: argparse.Namespace) -> None:
     """Load through the micro-batching tier (optionally via HTTP ingress).
 
-    ``--metrics-json`` dumps in a ``finally`` so an overload run killed
-    by SIGTERM still leaves its snapshot (the default SIGTERM action is
-    re-pointed at ``SystemExit`` for exactly that reason); the HTTP
-    serve-forever mode instead catches SIGTERM for a graceful drain.
+    ``--metrics-json`` (and ``--spans-json``) dump in a ``finally`` so an
+    overload run killed by SIGTERM still leaves its snapshot (the default
+    SIGTERM action is re-pointed at ``SystemExit`` for exactly that
+    reason); the HTTP serve-forever mode instead catches SIGTERM for a
+    graceful drain.
     """
     from repro import obs, serve
 
@@ -283,6 +284,9 @@ def _run_lut(args: argparse.Namespace) -> None:
             obs.registry().dump_json(args.metrics_json)
             print(f"[serve --lut] metrics snapshot -> {args.metrics_json}",
                   flush=True)
+        if args.spans_json:
+            obs.span_log().dump_json(args.spans_json)
+            print(f"[serve --lut] span log -> {args.spans_json}", flush=True)
     if st["retraces_after_warmup"] or st["compiler_runs_after_warmup"]:
         raise SystemExit("compile-once contract violated in steady state")
 
@@ -351,6 +355,10 @@ def main() -> None:
                     help="dump the full obs metrics snapshot (tier "
                     "histograms, engine + compiler counters) as JSON on "
                     "exit (see docs/observability.md)")
+    ap.add_argument("--spans-json", default=None, metavar="PATH",
+                    help="dump the process span log (every layer's timed "
+                    "spans, request and batch ids) as JSON on exit (see "
+                    "docs/observability.md)")
     ap.add_argument("--report-every-s", type=float, default=5.0,
                     help="periodic one-line stats report interval while "
                     "the load runs (0 disables)")

@@ -34,8 +34,10 @@ both reuse connections); anything fancier — TLS, HTTP/2, gRPC — is out of
 scope (see the ROADMAP's streaming-ingress open item).
 
 The per-request metrics (``ingress_requests_total`` by route/status,
-``ingress_rejected_total`` by reason, decode/infer stage histograms) are
-documented in docs/observability.md.
+``ingress_rejected_total`` by reason, decode/infer stage histograms) and
+spans (``ingress.request`` with its ``ingress.decode`` and
+``ingress.infer`` children, under one request id that the tier's spans
+carry on) are documented in docs/observability.md.
 """
 
 from __future__ import annotations
@@ -244,7 +246,8 @@ class HttpIngress:
                 pass
 
     async def _read_request(self, reader):
-        """One HTTP/1.x request -> (method, path, headers, body) or None.
+        """One HTTP/1.x request -> (method, path, version, headers, body,
+        perf_counter when its request line was read) or None.
 
         ``None`` means the peer closed between requests (normal keep-alive
         teardown); malformed framing raises ``ValueError`` and the
@@ -253,6 +256,7 @@ class HttpIngress:
         line = await reader.readline()
         if not line:
             return None
+        t_line = time.perf_counter()
         parts = line.decode("latin-1").split()
         if len(parts) != 3 or not parts[2].startswith("HTTP/"):
             raise ValueError(f"malformed request line {line!r}")
@@ -270,31 +274,39 @@ class HttpIngress:
         if length > self._cfg.max_body_bytes:
             raise _TooLarge(length)
         body = await reader.readexactly(length) if length else b""
-        return method, target.split("?", 1)[0], version, headers, body
+        return (method, target.split("?", 1)[0], version, headers, body,
+                t_line)
 
     async def _dispatch(self, req, writer) -> bool:
         t0 = time.perf_counter()
-        method, path, version, headers, body = req
+        method, path, version, headers, body, t_line = req
         keep_alive = (headers.get("connection", "").lower() != "close"
                       and not version.endswith("/1.0"))
         route = path if path in ("/v1/infer", "/healthz", "/metrics") else "*"
-        try:
-            if path == "/v1/infer":
-                if method != "POST":
-                    status, payload, ctype = 405, _err("method_not_allowed",
-                                                       "POST only"), None
+        # one request id for every span this request causes, set in this
+        # connection's task (the tier reads it in infer)
+        obs.REQUEST_ID.set(obs.new_id())
+        with obs.span("ingress.request", profile=False, parent=-1,
+                      start=t_line):
+            try:
+                if path == "/v1/infer":
+                    if method != "POST":
+                        status, payload, ctype = 405, _err(
+                            "method_not_allowed", "POST only"), None
+                    else:
+                        status, payload, ctype = await self._infer(headers,
+                                                                   body)
+                elif path == "/healthz":
+                    status, payload, ctype = self._healthz(method)
+                elif path == "/metrics":
+                    status, payload, ctype = self._metrics_page(method)
                 else:
-                    status, payload, ctype = await self._infer(headers, body)
-            elif path == "/healthz":
-                status, payload, ctype = self._healthz(method)
-            elif path == "/metrics":
-                status, payload, ctype = self._metrics_page(method)
-            else:
-                status, payload, ctype = 404, _err(
-                    "not_found", f"no route {path}"), None
-        except Exception as exc:                   # pragma: no cover
-            status, payload, ctype = 500, _err("internal", repr(exc)), None
-        await self._respond(writer, status, payload, ctype, keep_alive)
+                    status, payload, ctype = 404, _err(
+                        "not_found", f"no route {path}"), None
+            except Exception as exc:               # pragma: no cover
+                status, payload, ctype = 500, _err("internal",
+                                                   repr(exc)), None
+            await self._respond(writer, status, payload, ctype, keep_alive)
         self._metrics.requests.labels(route=route, status=str(status)).inc()
         self._metrics.request_seconds.observe(time.perf_counter() - t0)
         return keep_alive
@@ -321,14 +333,14 @@ class HttpIngress:
         if self._draining:
             m.rejected.labels(reason="draining").inc()
             return 503, _err("draining", "ingress is shutting down"), None
-        t_dec = time.perf_counter()
-        raw = (headers.get("content-type", "application/json")
-               .split(";")[0].strip() == "application/octet-stream")
-        try:
-            codes = self._decode(body, raw)
-        except ValueError as exc:
-            return 400, _err("bad_request", str(exc)), None
-        m.decode_seconds.observe(time.perf_counter() - t_dec)
+        with obs.span("ingress.decode") as dec:
+            raw = (headers.get("content-type", "application/json")
+                   .split(";")[0].strip() == "application/octet-stream")
+            try:
+                codes = self._decode(body, raw)
+            except ValueError as exc:
+                return 400, _err("bad_request", str(exc)), None
+        m.decode_seconds.observe(dec.end - dec.start)
 
         tenant = headers.get(self._cfg.tenant_header,
                              self._cfg.default_tenant) or \
@@ -347,19 +359,19 @@ class HttpIngress:
                     f"{quota.rate_rows_per_s:g} rows/s "
                     f"(burst {quota.burst:g})"), None
 
-        t_inf = time.perf_counter()
-        try:
-            out = await self.tier.infer(codes)
-        except TierOverloaded as exc:
-            m.rejected.labels(reason="overloaded").inc()
-            return 503, _err("overloaded", str(exc)), None
-        except RequestTimeout as exc:
-            m.rejected.labels(reason="timeout").inc()
-            return 408, _err("timeout", str(exc)), None
-        except TierClosed:
-            m.rejected.labels(reason="draining").inc()
-            return 503, _err("draining", "serving tier is stopping"), None
-        m.infer_seconds.observe(time.perf_counter() - t_inf)
+        with obs.span("ingress.infer", profile=False) as inf:
+            try:
+                out = await self.tier.infer(codes)
+            except TierOverloaded as exc:
+                m.rejected.labels(reason="overloaded").inc()
+                return 503, _err("overloaded", str(exc)), None
+            except RequestTimeout as exc:
+                m.rejected.labels(reason="timeout").inc()
+                return 408, _err("timeout", str(exc)), None
+            except TierClosed:
+                m.rejected.labels(reason="draining").inc()
+                return 503, _err("draining", "serving tier is stopping"), None
+        m.infer_seconds.observe(inf.end - inf.start)
 
         if raw:
             return 200, np.asarray(out, np.int8).tobytes(), \

@@ -60,7 +60,6 @@ import time
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 
 from repro import engine as rengine
 from repro import obs
@@ -74,10 +73,10 @@ class _TierMetrics:
     """This tier's labeled children in the process metrics registry.
 
     One instance per ServingTier: counters mirror the legacy ``stats()``
-    fields, the stage histograms are fed by the per-request spans
-    (queue wait / batch assembly / device time — see
-    docs/observability.md), and the two ``*_after_warmup`` gauges carry
-    the compile-once contract into the snapshot.
+    fields, the stage histograms time each request's legs (queue wait /
+    batch assembly / device time — see docs/observability.md), and the
+    two ``*_after_warmup`` gauges carry the compile-once contract into
+    the snapshot.
     """
 
     def __init__(self, tier_id: str) -> None:
@@ -113,7 +112,7 @@ class _TierMetrics:
             for cause in ("size", "deadline", "drain")}
         self.queue_wait = hist(
             "serve_queue_wait_seconds",
-            "enqueue -> flush decision (span leg: queue wait)")
+            "enqueue -> flush decision (queue wait)")
         self.assembly = hist(
             "serve_assembly_seconds",
             "flush -> device dispatch (batch concat + executor hand-off)")
@@ -183,9 +182,11 @@ class TierConfig:
 class _Request:
     codes: np.ndarray            # (rows, n_in) int32
     future: asyncio.Future       # resolves to (rows, n_out) np.ndarray
-    enqueue_t: float
+    enqueue_t: float             # loop clock, for the flush deadline
     deadline_t: float | None     # absolute launch deadline (None: never)
-    span: obs.Span               # enqueue -> flush -> dispatch -> done
+    enqueue_perf: float          # perf_counter, for the span log
+    request_id: int
+    parent_span: int             # the caller's open span (-1: none)
 
 
 class ServingTier:
@@ -217,6 +218,16 @@ class ServingTier:
         # engine on its one-trace-per-bucket contract, len(devices) keeps
         # the shard_map batch axis evenly divisible
         self._bucket_unit = math.lcm(block_b, len(devices))
+        # where a padded batch is put: split on the batch axis over a 1-D
+        # "data" mesh, or with one device uncommitted on the default
+        # device, as the engine itself puts host codes (so a direct call
+        # of the artifact shares the tier's trace)
+        if len(devices) == 1:
+            self._mesh, self._placement = None, None
+        else:
+            from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+            self._mesh = Mesh(np.asarray(devices), ("data",))
+            self._placement = NamedSharding(self._mesh, P("data"))
         self._forward, self._sharded_jit = self._make_forward()
         self._pending: collections.deque[_Request] = collections.deque()
         self._queued_rows = 0
@@ -228,21 +239,20 @@ class ServingTier:
         # now lives in the process metrics registry (labeled per tier);
         # stats() reads them back so its keys are unchanged
         self._metrics = _TierMetrics(str(next(_TIER_IDS)))
-        self._recent_spans: collections.deque[obs.Span] = (
-            collections.deque(maxlen=32))
         self._traces0 = 0
         self._compiler_runs0 = 0
 
     # -- forward construction ----------------------------------------------
 
     def _make_forward(self):
-        """(forward(padded) -> jax.Array, sharded jit fn or None)."""
+        """(forward(padded) -> jax.Array, sharded jit fn or None); the
+        padded batch is already on ``self._placement``."""
         net = self._net
-        if len(self._devices) == 1:
+        if self._mesh is None:
             return net, None
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
-        mesh = Mesh(np.asarray(self._devices), ("data",))
+        mesh = self._mesh
         # the slabs are replicated onto every device of the mesh once, here,
         # and passed as arguments: captured as constants they would stay
         # on the device they were built on.  Only the batch axis of the
@@ -253,21 +263,24 @@ class ServingTier:
             lambda codes, slabs: fn(codes, *slabs), mesh=mesh,
             in_specs=(P("data"), P()), out_specs=P("data"),
             check_vma=False))
-        in_sharding = NamedSharding(mesh, P("data"))
-
-        def forward(padded):
-            return fwd(jax.device_put(padded, in_sharding), arrays)
-        return forward, fwd
+        return (lambda padded: fwd(padded, arrays)), fwd
 
     def _bucket(self, rows: int) -> int:
         return -(-rows // self._bucket_unit) * self._bucket_unit
 
-    def _run_batch(self, batch: np.ndarray):
-        """Pad to the bucket, run the (possibly sharded) forward, slice.
+    def _run_batch(self, batch: np.ndarray, batch_id: int = -1,
+                   parent: int = -1):
+        """Pad to the bucket, put it on the device(s), run the (possibly
+        sharded) forward, copy the result back and slice it.
 
-        Returns ``(out, padded_rows, t_dispatch, t_done)`` — the two
-        timestamps bracket the device leg of every request span in the
-        batch (materializing the result included).
+        The three host calls are spans under ``parent`` with
+        ``batch_id``: ``tier.h2d`` (the put, which starts the copy),
+        ``tier.compute`` (the forward's dispatch) and ``tier.d2h`` (the
+        wait for the device and the copy back).  Nothing waits between
+        them, so the copy in, the dispatch and the kernel overlap as
+        before.  Returns ``(out, padded_rows, t_dispatch, t_done)`` — the
+        two timestamps bracket the device leg of every request in the
+        batch.
         """
         rows = batch.shape[0]
         padded_rows = self._bucket(rows)
@@ -275,13 +288,13 @@ class ServingTier:
             batch = np.concatenate(
                 [batch, np.zeros((padded_rows - rows, batch.shape[1]),
                                  dtype=batch.dtype)], axis=0)
-        t_dispatch = time.perf_counter()
-        if self._sharded_jit is None:
-            out = self._net(batch)           # the engine pads/slices itself
-        else:
-            out = self._forward(jnp.asarray(batch, dtype=jnp.int32))
-        out = np.asarray(out)[:rows]
-        return out, padded_rows, t_dispatch, time.perf_counter()
+        with obs.span("tier.h2d", parent=parent, batch=batch_id) as h2d:
+            x = jax.device_put(batch, self._placement)
+        with obs.span("tier.compute", parent=parent, batch=batch_id):
+            out = self._forward(x)
+        with obs.span("tier.d2h", parent=parent, batch=batch_id) as d2h:
+            out = np.asarray(out)[:rows]
+        return out, padded_rows, h2d.start, d2h.end
 
     def _trace_count(self) -> int:
         n = self._net.jit_cache_size()
@@ -381,8 +394,12 @@ class ServingTier:
         now = loop.time()
         deadline = (None if self._cfg.request_timeout_s is None
                     else now + self._cfg.request_timeout_s)
+        request_id = obs.REQUEST_ID.get()
+        if request_id < 0:
+            request_id = obs.new_id()
         req = _Request(arr, loop.create_future(), now, deadline,
-                       obs.Span("request"))
+                       time.perf_counter(), request_id,
+                       obs.CURRENT_SPAN.get())
         self._pending.append(req)
         self._queued_rows += rows
         self._metrics.requests.inc()
@@ -457,41 +474,43 @@ class ServingTier:
             if not batch:
                 continue
             cause = cause or "drain"
-            t_flush = time.perf_counter()   # the flush decision: queue
-            codes = (batch[0].codes if len(batch) == 1 else
-                     np.concatenate([r.codes for r in batch], axis=0))
-            try:
-                out, padded_rows, t_dispatch, t_done = (
-                    await loop.run_in_executor(None, self._run_batch, codes))
-            except Exception as exc:               # pragma: no cover
-                for req in batch:
-                    if not req.future.done():
-                        req.future.set_exception(
-                            TierError(f"batch execution failed: {exc!r}"))
-                continue
+            batch_id = obs.new_id()
+            # the flush decision ends the queue wait; the batch span runs
+            # until the batcher resumes with the results
+            with obs.span("tier.batch", profile=False, parent=-1,
+                          request=-1, batch=batch_id) as span:
+                codes = (batch[0].codes if len(batch) == 1 else
+                         np.concatenate([r.codes for r in batch], axis=0))
+                try:
+                    out, padded_rows, t_dispatch, t_done = (
+                        await loop.run_in_executor(
+                            None, self._run_batch, codes, batch_id,
+                            span.span_id))
+                except Exception as exc:           # pragma: no cover
+                    for req in batch:
+                        if not req.future.done():
+                            req.future.set_exception(TierError(
+                                f"batch execution failed: {exc!r}"))
+                    continue
+            t_flush = span.start
             self._metrics.batches.inc()
             self._metrics.padded_rows.inc(padded_rows)
             self._metrics.flush_by_cause[cause].inc()
             off = 0
+            log = obs.span_log()
             for req in batch:
                 n = req.codes.shape[0]
                 if not req.future.done():
                     req.future.set_result(out[off:off + n])
                 off += n
-                # close the request span with the batch's shared
-                # timestamps and feed the stage histograms
-                span = req.span
-                span.mark("flush", t_flush)
-                span.mark("dispatch", t_dispatch)
-                span.mark("done", t_done)
-                self._metrics.queue_wait.observe(
-                    span.duration("enqueue", "flush"))
-                self._metrics.assembly.observe(
-                    span.duration("flush", "dispatch"))
-                self._metrics.device.observe(
-                    span.duration("dispatch", "done"))
-                self._metrics.latency.observe(span.total)
-                self._recent_spans.append(span)
+                # the batch's shared timestamps give every request's legs
+                log.record("tier.queue", req.enqueue_perf, t_flush,
+                           parent=req.parent_span, request=req.request_id,
+                           batch=batch_id)
+                self._metrics.queue_wait.observe(t_flush - req.enqueue_perf)
+                self._metrics.assembly.observe(t_dispatch - t_flush)
+                self._metrics.device.observe(t_done - t_dispatch)
+                self._metrics.latency.observe(t_done - req.enqueue_perf)
         # post-drain: anything that slipped in after the final drain pass
         while self._pending:
             req = self._pending.popleft()
@@ -573,10 +592,6 @@ class ServingTier:
                 "p99_ms": h.quantile(0.99) * 1e3 if n else 0.0,
             }
         return out
-
-    def recent_spans(self) -> list[obs.Span]:
-        """The most recent completed request spans (bounded ring)."""
-        return list(self._recent_spans)
 
 
 async def serve_once(net, requests, config: TierConfig | None = None

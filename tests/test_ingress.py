@@ -295,14 +295,15 @@ def artifact(net, tmp_path_factory):
 def test_cli_http_smoke_end_to_end(net, artifact, tmp_path):
     """``serve --lut --http 0 --smoke``: open-loop load through a live
     localhost ingress, every response verified bit-exact, compile-once
-    counters zero, LoadReport and metrics snapshot dumped."""
+    counters zero, LoadReport, metrics snapshot and span log dumped."""
     report = str(tmp_path / "r.json")
     metrics = str(tmp_path / "m.json")
+    spans = str(tmp_path / "s.json")
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--lut",
          "--artifact", artifact, "--http", "0", "--smoke",
          "--report-every-s", "0", "--report-json", report,
-         "--metrics-json", metrics],
+         "--metrics-json", metrics, "--spans-json", spans],
         env=_subprocess_env(), capture_output=True, text=True,
         timeout=600, cwd=REPO)
     assert proc.returncode == 0, proc.stderr[-2000:]
@@ -320,6 +321,10 @@ def test_cli_http_smoke_end_to_end(net, artifact, tmp_path):
                for s in snap["ingress_requests_total"]["series"])
     assert all(s["count"] > 0
                for s in snap["ingress_infer_seconds"]["series"])
+    with open(spans) as fh:
+        names = json.load(fh)["columns"]["name"]
+    assert names.count("ingress.request") >= 16
+    assert names.count("tier.queue") >= 16
 
 
 def test_cli_http_sigterm_drains_and_dumps_metrics(net, artifact, tmp_path):

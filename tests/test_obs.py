@@ -8,19 +8,23 @@
   never shows a histogram whose ``count`` disagrees with its bucket
   counts;
 * **exposition** — a golden Prometheus text rendering and a JSON dump;
-* **spans** — mark ordering, derived leg durations;
+* **span log** — durations, parent ids and self time of nested spans,
+  the ring's bound (``dropped``, oldest start, ``holds``), the profiler
+  annotation ``obs.span`` opens, one request id from the HTTP ingress
+  through the tier's queue to its batch;
 * **stack wiring** — the serving tier feeds the stage histograms and its
   ``latency_breakdown()``/``LoadReport.breakdown`` stay JSON-safe on
   empty and tiny runs (the loadgen 0/1/2-request edges);
 * **hot-path overhead** — serving a request costs a bounded handful of
-  metric operations (regression-tested so an exporter can never creep
-  into the request path).
+  metric operations and span records (regression-tested so an exporter
+  can never creep into the request path).
 """
 
 import asyncio
 import io
 import json
 import math
+import sys
 import threading
 import time
 
@@ -198,17 +202,157 @@ def test_dump_json_roundtrip(tmp_path):
 
 
 def test_span_marks_and_durations():
-    span = obs.Span("request", t=10.0)
-    span.mark("flush", 10.5)
-    span.mark("dispatch", 10.6)
-    span.mark("done", 11.0)
-    assert [s for s, _ in span.marks] == list(obs.REQUEST_STAGES)
-    assert span.duration("enqueue", "flush") == pytest.approx(0.5)
-    assert span.durations() == pytest.approx(
-        {"enqueue->flush": 0.5, "flush->dispatch": 0.1,
-         "dispatch->done": 0.4})
-    assert span.total == pytest.approx(1.0)
-    assert span.as_dict()["stages"] == list(obs.REQUEST_STAGES)
+    """Records written after the fact read back as columns, cut to the
+    spans that start in ``[t0, t1)``, with their durations."""
+    log = obs.SpanLog(capacity=16)
+    q = log.record("tier.queue", 10.0, 10.5, request=7, batch=3)
+    b = log.record("tier.batch", 10.5, 11.0, batch=3)
+    log.record("tier.h2d", 10.6, 10.7, parent=b, batch=3)
+    log.record("tier.queue", 12.0, 12.25, request=8, batch=4)
+    cols = log.columns()
+    assert list(cols["name"]) == ["tier.queue", "tier.batch", "tier.h2d",
+                                  "tier.queue"]
+    assert list(cols["span"][:2]) == [q, b] and q != b
+    assert list(cols["request"]) == [7, -1, -1, 8]
+    assert list(cols["batch"]) == [3, 3, 3, 4]
+    assert list(cols["parent"]) == [-1, -1, b, -1]
+    np.testing.assert_allclose(log.durations("tier.queue"), [0.5, 0.25])
+    np.testing.assert_allclose(log.durations("tier.queue", 11.0), [0.25])
+    np.testing.assert_allclose(log.durations("tier.queue", 9.0, 12.0),
+                               [0.5])
+    assert list(log.columns(10.5, 10.6)["name"]) == ["tier.batch"]
+    assert log.durations("never.recorded").size == 0
+    assert log.self_seconds("never.recorded").size == 0
+
+
+def test_nested_spans_parent_ids_and_self_time():
+    """``obs.span`` blocks nest by ``CURRENT_SPAN``: the inner span's
+    parent is the outer one, and the outer span's self time is its
+    duration less its direct children's (not its grandchildren's)."""
+    t0 = time.perf_counter()
+    token = obs.REQUEST_ID.set(41)
+    try:
+        with obs.span("test.outer", profile=False) as outer:
+            time.sleep(0.01)
+            with obs.span("test.child") as child:
+                with obs.span("test.grandchild") as grand:
+                    time.sleep(0.02)
+            with obs.span("test.child") as child2:
+                time.sleep(0.01)
+    finally:
+        obs.REQUEST_ID.reset(token)
+    assert obs.CURRENT_SPAN.get() == -1
+    log = obs.span_log()
+    cols = log.columns(t0)
+    by_id = {int(s): i for i, s in enumerate(cols["span"])}
+    assert cols["parent"][by_id[child.span_id]] == outer.span_id
+    assert cols["parent"][by_id[child2.span_id]] == outer.span_id
+    assert cols["parent"][by_id[grand.span_id]] == child.span_id
+    assert cols["parent"][by_id[outer.span_id]] == -1
+    assert set(cols["request"][list(by_id.values())]) == {41}
+    whole = outer.end - outer.start
+    kids = (child.end - child.start) + (child2.end - child2.start)
+    (own,) = log.self_seconds("test.outer", t0)
+    assert own == pytest.approx(whole - kids)
+    assert 0.009 <= own < whole - 0.03
+    (leaf,) = log.self_seconds("test.grandchild", t0)
+    assert leaf == pytest.approx(grand.end - grand.start)
+
+
+def test_span_log_ring_bound_and_oldest_start():
+    """A full ring overwrites its oldest records, counts them in
+    ``dropped``, and no longer holds a window that began before the
+    oldest start it kept."""
+    log = obs.SpanLog(capacity=4)
+    assert log.oldest_start is None and log.dropped == 0
+    assert log.holds(0.0)
+    for i in range(10):
+        log.record("x", float(i), i + 0.5)
+    assert log.dropped == 6
+    assert log.oldest_start == 6.0
+    np.testing.assert_allclose(log.columns()["start"], [6, 7, 8, 9])
+    # the record that started at 5.0 was overwritten: a window from 5.0
+    # on is no longer whole, one from 5.5 on is
+    assert log.holds(5.5) and not log.holds(5.0)
+    with pytest.raises(ValueError):
+        obs.SpanLog(capacity=0)
+
+
+def test_span_log_concurrent_writers_lose_nothing():
+    """Threads record into one log at once (the event loop and the
+    tier's executor do): every record lands once, under its own name,
+    and a ring too small for them all counts exactly what it dropped."""
+    n_threads, per = 16, 3000
+    logs = (obs.SpanLog(capacity=1 << 17), obs.SpanLog(capacity=10000))
+
+    def work(k):
+        for i in range(per):
+            for log in logs:
+                log.record(f"w{k % 3}", float(i), i + 1.0, request=k,
+                           batch=i)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(k,))
+                   for k in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    whole, small = logs
+    cols = whole.columns()
+    assert whole.dropped == 0 and len(cols["span"]) == n_threads * per
+    assert len(set(cols["span"].tolist())) == n_threads * per
+    for k in range(n_threads):
+        mine = cols["request"] == k
+        assert sorted(cols["batch"][mine].tolist()) == list(range(per))
+        assert set(cols["name"][mine]) == {f"w{k % 3}"}
+    assert small.dropped == n_threads * per - 10000
+    assert len(small.columns()["span"]) == 10000
+
+
+def test_span_opens_a_trace_annotation_of_its_name(monkeypatch):
+    """``obs.span`` mirrors its block into the profiler (a
+    ``jax.profiler.TraceAnnotation`` of the same name, open while the
+    block runs); ``profile=False`` opens none."""
+    import jax.profiler
+    events = []
+
+    class Annotation:
+        def __init__(self, name, **kw):
+            self.name = name
+
+        def __enter__(self):
+            events.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            events.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    with obs.span("test.annotated"):
+        events.append(("body", None))
+    with obs.span("test.log_only", profile=False):
+        pass
+    assert events == [("enter", "test.annotated"), ("body", None),
+                      ("exit", "test.annotated")]
+
+
+def test_span_log_dump_json(tmp_path):
+    t0 = time.perf_counter()
+    with obs.span("test.dumped", profile=False):
+        pass
+    path = obs.span_log().dump_json(str(tmp_path / "spans.json"))
+    with open(path) as f:
+        dump = json.load(f)
+    assert dump["clock"] == "time.perf_counter"
+    assert dump["capacity"] == obs.span_log().capacity
+    cols = dump["columns"]
+    i = cols["name"].index("test.dumped")
+    assert cols["start"][i] >= t0 and cols["end"][i] >= cols["start"][i]
 
 
 def test_summary_line_and_periodic_reporter():
@@ -254,25 +398,66 @@ def net():
 def test_tier_feeds_stage_histograms_and_breakdown(net):
     async def main():
         async with serve.ServingTier(net) as tier:
+            t0 = time.perf_counter()
             await asyncio.gather(*[
                 tier.infer(np.zeros((2, net.n_in), np.int32))
                 for _ in range(5)])
-            return tier.latency_breakdown(), tier.recent_spans()
+            return tier.latency_breakdown(), t0
 
-    breakdown, spans = asyncio.run(main())
+    breakdown, t0 = asyncio.run(main())
     assert set(breakdown) == {"queue_wait", "assembly", "device", "total"}
     for stage, leg in breakdown.items():
         assert leg["count"] == 5, stage
         assert leg["mean_ms"] >= 0.0
         assert leg["p50_ms"] <= leg["p99_ms"]
-    # a request's legs sum to its total, and the ring kept the spans
-    assert len(spans) == 5
-    for span in spans:
-        legs = span.durations()
-        assert sum(legs.values()) == pytest.approx(span.total)
-        assert [s for s, _ in span.marks] == list(obs.REQUEST_STAGES)
+    # the log kept one queue span per request, each joined by its batch
+    # id to a batch span whose three device legs are its children and
+    # lie inside it, after the queue wait
+    cols = obs.span_log().columns(t0)
+    name = cols["name"]
+    queue = np.flatnonzero(name == "tier.queue")
+    assert len(queue) == 5
+    assert len(set(cols["request"][queue])) == 5
+    for q in queue:
+        (b,) = np.flatnonzero((name == "tier.batch")
+                              & (cols["batch"] == cols["batch"][q]))
+        assert cols["end"][q] == cols["start"][b]
+        legs = np.flatnonzero(cols["parent"] == cols["span"][b])
+        assert sorted(name[legs]) == ["tier.compute", "tier.d2h",
+                                      "tier.h2d"]
+        assert (cols["start"][legs] >= cols["start"][b]).all()
+        assert (cols["end"][legs] <= cols["end"][b]).all()
     # breakdown is JSON-strict (no NaN): the --metrics-json contract
     json.dumps(breakdown, allow_nan=False)
+
+
+def test_request_id_from_ingress_through_tier_to_batch(net):
+    """One HTTP request's id is on its ``ingress.request``, its
+    ``ingress.decode`` and ``ingress.infer`` children and its
+    ``tier.queue`` span, whose batch id names the ``tier.batch`` that
+    served it; the decode and infer spans lie inside the request's."""
+    t0 = time.perf_counter()
+    with serve.BackgroundIngress(net) as ing:
+        for rows in (1, 3):
+            asyncio.run(serve.http_infer(
+                "127.0.0.1", ing.port, np.ones((rows, net.n_in), np.int32)))
+    cols = obs.span_log().columns(t0)
+    name = cols["name"]
+    reqs = np.flatnonzero(name == "ingress.request")
+    assert len(reqs) == 2
+    for r in reqs:
+        rid, sid = cols["request"][r], cols["span"][r]
+        mine = cols["request"] == rid
+        kids = np.flatnonzero(mine & (cols["parent"] == sid))
+        assert sorted(name[kids]) == ["ingress.decode", "ingress.infer"]
+        assert (cols["start"][kids] >= cols["start"][r]).all()
+        assert (cols["end"][kids] <= cols["end"][r]).all()
+        (infer,) = kids[name[kids] == "ingress.infer"]
+        (q,) = np.flatnonzero(mine & (name == "tier.queue"))
+        assert cols["parent"][q] == cols["span"][infer]
+        (b,) = np.flatnonzero((name == "tier.batch")
+                              & (cols["batch"] == cols["batch"][q]))
+        assert cols["batch"][q] >= 0 and cols["request"][b] == -1
 
 
 def test_tier_metrics_in_process_registry(net):
@@ -378,8 +563,10 @@ def test_request_path_metric_overhead_is_bounded(net, monkeypatch):
     """Serving a request costs a bounded handful of metric ops: 2 counter
     incs at submit, 4 histogram observes at completion, ~3 counter incs
     amortized per batch.  A metrics/tracing change that adds per-request
-    rendering, snapshotting or extra metric traffic trips this budget."""
+    rendering, snapshotting or extra metric traffic trips this budget.
+    The span log gets at most 4 records a request and 7 a batch."""
     ops = {"n": 0}
+    records = []
 
     def counted(orig):
         def wrapper(self, *a, **kw):
@@ -392,6 +579,13 @@ def test_request_path_metric_overhead_is_bounded(net, monkeypatch):
     monkeypatch.setattr(obs.Gauge, "set", counted(obs.Gauge.set))
     monkeypatch.setattr(obs.Histogram, "observe",
                         counted(obs.Histogram.observe))
+    record = obs.SpanLog.record
+
+    def recorded(self, name, *a, **kw):
+        records.append(name)
+        return record(self, name, *a, **kw)
+
+    monkeypatch.setattr(obs.SpanLog, "record", recorded)
 
     n_requests = 24
     reqs = [np.full((2, net.n_in), i % 4, np.int32)
@@ -403,3 +597,9 @@ def test_request_path_metric_overhead_is_bounded(net, monkeypatch):
     assert ops["n"] <= 10 * n_requests, (
         f"{ops['n']} metric ops for {n_requests} requests — the request "
         "path grew metric work beyond the counter-increment budget")
+    # warm-up runs one bucket: a batch's worth of records more
+    n_batches = records.count("tier.batch")
+    assert 1 <= n_batches <= n_requests
+    assert len(records) <= 4 * n_requests + 7 * (n_batches + 1), (
+        f"{len(records)} span records for {n_requests} requests in "
+        f"{n_batches} batches: {sorted(set(records))}")
