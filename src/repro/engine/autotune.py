@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
-from repro.kernels.lut_lookup import DEFAULT_BLOCK_B
+from repro.kernels.lut_lookup import DEFAULT_BLOCK_B, two_level_exact
 from repro.kernels.ops import interpret_mode
 from repro.kernels.lut_network import (build_mixed_network_slabs,
                                        build_network_slabs)
@@ -210,6 +210,9 @@ def autotune_network(uniform_triples, mixed_tables=None, *,
     codes = jnp.asarray(np.asarray(codes, dtype=np.int32))
     batch = int(codes.shape[0])
     interp = interpret_mode()
+    # the per-layer variants' gathers, decided as engine.per_layer_gathers
+    # does (uncounted: only the artifact's own layers are counted)
+    two_level = tuple(two_level_exact(t, b) for _, t, b in uniform_triples)
 
     # one build per (layout, pack) — slabs are block_b-independent
     built: dict[tuple[str, bool], object] = {}
@@ -248,7 +251,8 @@ def autotune_network(uniform_triples, mixed_tables=None, *,
         idx_tabs = tuple((i, t) for i, t, _ in p)
         bws = tuple(b for _, _, b in p)
         return lambda c=x: _eng._per_layer_forward(
-            c, idx_tabs, bws=bws, block_b=v.block_b, interpret=interp)
+            c, idx_tabs, bws=bws, two_level=two_level, block_b=v.block_b,
+            interpret=interp)
 
     timings: dict[str, float] = {}
     by_key: dict[str, PlanVariant] = {}

@@ -54,7 +54,8 @@ from repro.checkpoint.ckpt import load_arrays, save_arrays
 from repro.compile.pipeline import CompileStats, OptimizeResult
 from repro.engine.autotune import ExecutionPlan, autotune_network
 from repro.kernels import ref
-from repro.kernels.lut_lookup import DEFAULT_BLOCK_B, lut_lookup_pallas
+from repro.kernels.lut_lookup import (DEFAULT_BLOCK_B, lut_lookup_pallas,
+                                      two_level_exact, two_level_table)
 from repro.kernels.lut_network import (LayerMeta, MixedGroupMeta,
                                        MixedLayerMeta, MixedNetworkSlabs,
                                        NetworkSlabs,
@@ -91,6 +92,10 @@ _M_COMPILER_RUNS = obs.registry().counter(
 _M_BUILDS = obs.registry().counter(
     "engine_builds_total", "CompiledLUTNet builds by chosen layout",
     labels=("layout",))
+_M_PER_LAYER_GATHER = obs.registry().counter(
+    "engine_per_layer_gather_total",
+    "layers of per-layer artifacts by the table gather their kernel takes",
+    labels=("gather",))
 _M_SLAB_BUILD = obs.registry().histogram(
     "engine_slab_build_seconds",
     "host-side slab construction time per compile_network build")
@@ -139,9 +144,13 @@ def _mixed_forward(codes, idx_slab, shift_slab, width_slab, table_slabs, *,
                                     interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("bws", "block_b", "interpret"))
-def _per_layer_forward(codes, idx_tabs, *, bws, block_b, interpret):
-    for (idx, tab), bw in zip(idx_tabs, bws):
+@functools.partial(jax.jit, static_argnames=("bws", "two_level", "block_b",
+                                             "interpret"))
+def _per_layer_forward(codes, idx_tabs, *, bws, two_level, block_b,
+                       interpret):
+    for (idx, tab), bw, two in zip(idx_tabs, bws, two_level):
+        if two:
+            tab = two_level_table(tab)
         codes = lut_lookup_pallas(codes, idx, tab, bw, block_b=block_b,
                                   interpret=interpret)
     return codes
@@ -172,7 +181,10 @@ class CompiledLUTNet:
     * ``"reference"`` — the plain-jnp per-layer oracle (``use_pallas=False``
       compatibility; jitted but kernel-free).
 
-    Exactly one of ``slabs`` / ``layers`` is populated.  ``plan`` is the
+    Exactly one of ``slabs`` / ``layers`` is populated.  ``two_level``
+    (per-layer layout only) says, per layer, whether its kernel takes the
+    two-level MXU gather or the integer compare loop, as
+    ``per_layer_gathers`` decided from the tables at build time.  ``plan`` is the
     :class:`~repro.engine.autotune.ExecutionPlan` that made the decision
     — heuristic, autotuned or synthesized from a pre-autotune artifact;
     its compat properties (``plan.reason``, ``plan.slab_bytes``, ...)
@@ -192,6 +204,7 @@ class CompiledLUTNet:
     stats: CompileStats | None
     slabs: NetworkSlabs | MixedNetworkSlabs | None = None
     layers: tuple[tuple[jax.Array, jax.Array, int], ...] | None = None
+    two_level: tuple[bool, ...] | None = None
 
     def __call__(self, codes) -> jax.Array:
         """(batch, n_in) int codes -> (batch, n_out) int32 codes.
@@ -252,6 +265,8 @@ class CompiledLUTNet:
         else:
             arrays = (tuple((idx, tab) for idx, tab, _ in self.layers),)
             static = {"bws": tuple(bw for _, _, bw in self.layers)}
+        if self.layout == "per_layer":
+            static["two_level"] = self.two_level
         if self.layout != "reference":
             static.update(block_b=self.block_b, interpret=interpret_mode())
         return functools.partial(_FORWARDS[self.layout], **static), arrays
@@ -362,6 +377,7 @@ def load(path: str) -> CompiledLUTNet:
             f"format 4, which no TPU kernel can read; compile it again")
     slabs = None
     layers = None
+    two_level = None
     if layout == "mixed":
         lm = tuple(
             MixedLayerMeta(m["n_out"], m["fan_in"],
@@ -387,20 +403,38 @@ def load(path: str) -> CompiledLUTNet:
                              jnp.asarray(arrays["table_slab"]),
                              lm, bool(meta["packed"]))
     else:
-        layers = tuple(
-            (jnp.asarray(arrays[f"idx_{li}"]),
-             jnp.asarray(arrays[f"table_{li}"]), int(bw))
-            for li, bw in enumerate(meta["bws"]))
+        triples = [(arrays[f"idx_{li}"], arrays[f"table_{li}"], int(bw))
+                   for li, bw in enumerate(meta["bws"])]
+        layers = tuple((jnp.asarray(i), jnp.asarray(t), bw)
+                       for i, t, bw in triples)
+        if layout == "per_layer":
+            two_level = per_layer_gathers(triples)
     _M_LOADS.inc()
     return CompiledLUTNet(layout=layout, n_in=int(meta["n_in"]),
                           n_out=int(meta["n_out"]),
                           block_b=int(meta["block_b"]), plan=plan,
-                          stats=stats, slabs=slabs, layers=layers)
+                          stats=stats, slabs=slabs, layers=layers,
+                          two_level=two_level)
 
 
 # ---------------------------------------------------------------------------
 # compile_network: the one place the compile/cost/build/jit decision lives
 # ---------------------------------------------------------------------------
+
+
+def per_layer_gathers(triples) -> tuple[bool, ...]:
+    """Per layer of a per-layer artifact, whether its kernel takes the
+    two-level MXU gather (``two_level_exact``: every table code and input
+    code within the exact bf16 range) or keeps the integer compare loop.
+
+    Decided once, from the host tables, and counted once per layer in
+    ``engine_per_layer_gather_total``.
+    """
+    two_level = tuple(two_level_exact(t, bw) for _, t, bw in triples)
+    for two in two_level:
+        _M_PER_LAYER_GATHER.labels(
+            gather="two_level" if two else "compare").inc()
+    return two_level
 
 
 def _as_triples(layers) -> list[tuple[np.ndarray, np.ndarray, int]]:
@@ -498,7 +532,8 @@ def compile_network(layers, *, optimize_level: int | None = None,
         n_out = int(np.asarray(triples[-1][1]).shape[0])
         return CompiledLUTNet(layout="per_layer", n_in=in_features,
                               n_out=n_out, block_b=plan.block_b, plan=plan,
-                              stats=stats, layers=built)
+                              stats=stats, layers=built,
+                              two_level=per_layer_gathers(triples))
 
     if res is not None and use_pallas and fused:
         mixed = res.mixed_tables
@@ -550,7 +585,8 @@ def compile_network(layers, *, optimize_level: int | None = None,
     return CompiledLUTNet(
         layout=layout, n_in=in_features, n_out=n_out, block_b=block_b,
         plan=ExecutionPlan.from_fused(cost, layout, block_b),
-        stats=stats, layers=jl)
+        stats=stats, layers=jl,
+        two_level=per_layer_gathers(triples) if use_pallas else None)
 
 
 # ---------------------------------------------------------------------------

@@ -8,18 +8,30 @@ Scattered gathers are slow on TPU (no hardware gather across lanes), so both
 gathers are expressed as **one-hot contractions on the MXU**:
 
   * fan-in gather:  sel[o,k,i] = (indices[o,k] == i); g = sel · codes
-  * table gather:   out[b,o]  += Σ_e (entry[b,o] == e+off) * table[o,e+off]
-    streamed over E in chunks so the compare tensor stays inside VMEM.
+  * table gather, two-level (``_table_gather_two_level``): the packed
+    entry index splits into a low half, gathered by one batched matmul
+    against its one-hot, and a high half, a sqrt(E)-way select.  This
+    module's per-layer kernel and both fused kernels (``lut_network``)
+    share it; it is exact for codes below ``EXACT_CODE_LIMIT``.
+  * table gather, compare: out[b,o] += Σ_e (entry[b,o] == e+off) *
+    table[o,e+off], streamed over E in chunks so the compare tensor stays
+    inside VMEM.  Integer and exact for any int32 table, at the cost of
+    one compare-select per entry; the per-layer kernel keeps it only for
+    a layer whose table codes or input codes fall outside the exact bf16
+    range (``two_level_exact``, decided from the table on the host).
 
-Grid: (batch tiles × neuron tiles); per step the kernel sees a
-(block_b, I) code slab, a (block_o, FI) index slab and a (block_o, E) table
-slab — all VMEM-resident under the default tile sizes (see ops.lut_lookup
-for the sizing arithmetic).
+``lut_lookup_pallas`` takes the gather from the table's layout: a 3-D
+(O, n_hi, n_lo) table (``two_level_table``) takes the two-level gather on
+a (neuron tiles × batch tiles) grid, batch inner, so each neuron tile's
+table is fetched once per layer; a 2-D (O, E) table takes the compare on a
+(batch tiles × neuron tiles) grid.
 """
 
 from __future__ import annotations
 
 import functools
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +51,11 @@ EXACT_CODE_LIMIT = 256
 
 # elements of the per-layer kernel's compare tensor per grid step
 _COMPARE_ELEMS = 1 << 20
+
+# VMEM bytes the two-level gather's per-step values may take (the f32
+# (block_o, n_hi, block_b) partials and the bf16 low one-hot, the fan-in
+# gather's product): this caps the per-layer kernel's batch tile
+_TWO_LEVEL_STEP_BYTES = 12 << 20
 
 
 def pack_fan_in_entries(codes: jax.Array, idx: jax.Array,
@@ -100,6 +117,75 @@ def pack_fan_in_entries_mixed(codes: jax.Array, idx: jax.Array,
     return jnp.sum(g << shifts[:, :, None], axis=1)        # (bo, bb)
 
 
+def _gather_split(n_entries: int) -> tuple[int, int]:
+    """(n_hi, n_lo) of the two-level gather over ``n_entries`` (a power of
+    two): entry ``e`` sits at ``[e >> lo_bits, e & (n_lo - 1)]``."""
+    lo_bits = (n_entries.bit_length() - 1) // 2
+    return n_entries >> lo_bits, 1 << lo_bits
+
+
+def _table_gather_two_level(entry: jax.Array,
+                            table: jax.Array) -> jax.Array:
+    """Gather table[o, entry[o, b]] for all (o, b): (bo, bb) -> (bb, bo).
+
+    ``table`` is (bo, n_hi, n_lo) int32 (or bf16), each neuron's table
+    already in the host-side (n_hi, n_lo) layout (``_gather_split``).
+    Instead of an elementwise compare/select over all table entries, the
+    gather splits the packed entry index into low/high halves: the low
+    half is gathered with one *batched matmul* against its one-hot (MXU
+    work), which collapses the entry axis from E to sqrt(E); the high half
+    then costs only an O(B*O*sqrt(E)) elementwise select.  Exact — bf16
+    one-hot contractions are exact on codes below ``EXACT_CODE_LIMIT`` —
+    at matmul throughput instead of compare/select throughput.  Shared by
+    the per-layer kernel here and the uniform and mixed-width fused
+    kernels (the entry packing is what differs between them); only a
+    per-layer table outside that exact range still takes the compare
+    loop (``_kernel``).
+    """
+    bo, n_hi, n_lo = table.shape
+    bb = entry.shape[1]
+    lo = entry & (n_lo - 1)
+    hi = entry >> (n_lo.bit_length() - 1)
+
+    # batch stays on lanes throughout: both one-hots broadcast the entry
+    # halves along sublanes, so no in-kernel relayout is needed
+    lo_iota = jax.lax.broadcasted_iota(jnp.int32, (bo, n_lo, bb), 1)
+    oh_lo = (lo[:, None, :] == lo_iota).astype(jnp.bfloat16)  # (bo,n_lo,bb)
+    # (bo, n_hi, n_lo) x (bo, n_lo, bb) -> (bo, n_hi, bb), batched over bo
+    part = jax.lax.dot_general(
+        table.astype(jnp.bfloat16), oh_lo, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+    hi_iota = jax.lax.broadcasted_iota(jnp.int32, (bo, n_hi, bb), 1)
+    out = jnp.sum(jnp.where(hi[:, None, :] == hi_iota, part, 0.0),
+                  axis=1)                                    # (bo, bb)
+    return out.astype(jnp.int32).T                           # (bb, bo)
+
+
+def two_level_exact(table, bw_in: int) -> bool:
+    """Whether the two-level gather serves this layer exactly: every code
+    of the concrete (host) ``table`` lies in [0, ``EXACT_CODE_LIMIT``) and
+    every input code, of ``bw_in`` bits, lies below it too."""
+    t = np.asarray(table)
+    return (1 << int(bw_in) <= EXACT_CODE_LIMIT
+            and int(np.min(t, initial=0)) >= 0
+            and int(np.max(t, initial=0)) < EXACT_CODE_LIMIT)
+
+
+def two_level_table(table: jax.Array) -> jax.Array:
+    """(O, E) int32 table -> the (O, n_hi, n_lo) bf16 layout the two-level
+    gather contracts.  Only for a table ``two_level_exact`` admits: bf16
+    holds its codes exactly.  Mosaic cannot split the lane axis inside a
+    kernel, so this reshape runs ahead of the ``pallas_call``."""
+    n_out, n_entries = table.shape
+    return table.reshape(n_out, *_gather_split(n_entries)).astype(
+        jnp.bfloat16)
+
+
+def _two_level_kernel(codes_ref, idx_ref, table_ref, out_ref, *, bw_in: int):
+    entry = pack_fan_in_entries(codes_ref[...], idx_ref[...], bw_in)
+    out_ref[...] = _table_gather_two_level(entry, table_ref[...])
+
+
 def _kernel(codes_ref, idx_ref, table_ref, out_ref, *, bw_in: int,
             e_chunk: int):
     codes = codes_ref[...]                      # (bb, I) int32
@@ -129,15 +215,24 @@ def lut_lookup_pallas(codes: jax.Array, indices: jax.Array, table: jax.Array,
                       block_o: int = 128,
                       e_chunk: int = 512,
                       interpret: bool = False) -> jax.Array:
-    """(batch, I) codes -> (batch, O) codes through per-neuron truth tables."""
+    """(batch, I) codes -> (batch, O) codes through per-neuron truth tables.
+
+    A 3-D ``table`` (``two_level_table``) takes the two-level gather; a
+    2-D (O, E) int32 table takes the compare loop, streamed in
+    ``e_chunk``-entry chunks.
+    """
     batch, n_in = codes.shape
     n_out, fan_in = indices.shape
-    n_entries = table.shape[1]
     if batch == 0:
         # a zero-size grid (min(block_b, 0) == 0) is ill-formed; the empty
         # result needs no kernel at all
         return jnp.zeros((0, n_out), dtype=jnp.int32)
     block_o = min(block_o, n_out)
+    if table.ndim == 3:
+        return _lookup_two_level(codes, indices, table, bw_in,
+                                 block_b=block_b, block_o=block_o,
+                                 interpret=interpret)
+    n_entries = table.shape[1]
     e_chunk = min(e_chunk, n_entries)
     # the streamed compare holds a (block_o, block_b, e_chunk) tensor: cap
     # the batch tile so it stays near 4 MiB of the chip's scoped VMEM
@@ -157,6 +252,35 @@ def lut_lookup_pallas(codes: jax.Array, indices: jax.Array, table: jax.Array,
             pl.BlockSpec((block_o, n_entries), lambda b, o: (o, 0)),
         ],
         out_specs=pl.BlockSpec((block_b, block_o), lambda b, o: (b, o)),
+        out_shape=jax.ShapeDtypeStruct((batch, n_out), jnp.int32),
+        interpret=interpret,
+    )(codes, indices, table)
+
+
+def _lookup_two_level(codes, indices, table, bw_in, *, block_b, block_o,
+                      interpret):
+    batch, n_in = codes.shape
+    n_out, fan_in = indices.shape
+    _, n_hi, n_lo = table.shape
+    # per batch row, a step holds the f32 partials and their select
+    # (2 x n_hi), the bf16 low one-hot and the fan-in gather's f32 product
+    # (with its int32 copy) for each of its block_o neurons
+    row_bytes = block_o * (8 * n_hi + 2 * n_lo + 8 * fan_in)
+    cap = max(8, _TWO_LEVEL_STEP_BYTES // row_bytes // 8 * 8)
+    block_b = min(block_b, batch, cap)
+    # neuron tiles outer, batch inner: the table block's index stays put
+    # across the inner loop, so the pipeline fetches each tile's table once
+    grid = (pl.cdiv(n_out, block_o), pl.cdiv(batch, block_b))
+
+    return pl.pallas_call(
+        functools.partial(_two_level_kernel, bw_in=bw_in),
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((block_b, n_in), lambda o, b: (b, 0)),
+            pl.BlockSpec((block_o, fan_in), lambda o, b: (o, 0)),
+            pl.BlockSpec((block_o, n_hi, n_lo), lambda o, b: (o, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((block_b, block_o), lambda o, b: (b, o)),
         out_shape=jax.ShapeDtypeStruct((batch, n_out), jnp.int32),
         interpret=interpret,
     )(codes, indices, table)

@@ -36,12 +36,10 @@ stacks (and stacks shrunk by ``repro.compile``'s dead-neuron elimination)
 get proportionally smaller slabs, where the old box layout paid
 ``L * O_max`` rows regardless.
 
-Per layer the fan-in gather is the same one-hot MXU contraction as
-``lut_lookup``, but the table gather is upgraded from a streamed
-compare/select to a *two-level one-hot gather* (see ``_layer_step``): the
-bulk of the work becomes a batched matmul, which is where the fused
-engine's measured speedup over the per-layer path comes from on top of
-the saved HBM round trips.
+Per layer the fan-in gather and the table gather are the per-layer
+kernel's own: the one-hot MXU contraction and the *two-level one-hot
+gather* (``lut_lookup._table_gather_two_level``), whose bulk is a batched
+matmul.  What the fused kernels add is the saved HBM round trips.
 
 Mixed-width layout (``MixedNetworkSlabs`` / ``lut_network_mixed_pallas``)
 — the compiler-exact variant of the same engine.  ``repro.compile``'s
@@ -92,6 +90,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.lut_lookup import (DEFAULT_BLOCK_B, EXACT_CODE_LIMIT,
+                                      _gather_split,
+                                      _table_gather_two_level,
                                       pack_fan_in_entries,
                                       pack_fan_in_entries_mixed)
 
@@ -318,49 +318,6 @@ def _check_exact(in_range: bool) -> None:
             f"exact only for input and output codes in [0, "
             f"{EXACT_CODE_LIMIT}) — use the per-layer path (fused=False) "
             "for wider codes")
-
-
-def _gather_split(n_entries: int) -> tuple[int, int]:
-    """(n_hi, n_lo) of the two-level gather over ``n_entries`` (a power of
-    two): entry ``e`` sits at ``[e >> lo_bits, e & (n_lo - 1)]``."""
-    lo_bits = (n_entries.bit_length() - 1) // 2
-    return n_entries >> lo_bits, 1 << lo_bits
-
-
-def _table_gather_two_level(entry: jax.Array,
-                            table: jax.Array) -> jax.Array:
-    """Gather table[o, entry[o, b]] for all (o, b): (bo, bb) -> (bb, bo).
-
-    ``table`` is (bo, n_hi, n_lo) int32, each neuron's table already in
-    the host-side (n_hi, n_lo) layout (``_gather_split``).  Unlike the
-    per-layer ``lut_lookup`` kernel (which streams an elementwise
-    compare/select over all table entries), the gather here splits the
-    packed entry index into low/high halves: the low half is gathered with
-    one *batched matmul* against its one-hot (MXU work), which collapses
-    the entry axis from E to sqrt(E); the high half then costs only an
-    O(B*O*sqrt(E)) elementwise select.  Same exact result — bf16 one-hot
-    contractions are exact on codes below ``EXACT_CODE_LIMIT`` — at matmul
-    throughput instead of compare/select throughput.  Shared by the
-    uniform and mixed-width fused kernels (the entry packing is what
-    differs between them).
-    """
-    bo, n_hi, n_lo = table.shape
-    bb = entry.shape[1]
-    lo = entry & (n_lo - 1)
-    hi = entry >> (n_lo.bit_length() - 1)
-
-    # batch stays on lanes throughout: both one-hots broadcast the entry
-    # halves along sublanes, so no in-kernel relayout is needed
-    lo_iota = jax.lax.broadcasted_iota(jnp.int32, (bo, n_lo, bb), 1)
-    oh_lo = (lo[:, None, :] == lo_iota).astype(jnp.bfloat16)  # (bo,n_lo,bb)
-    # (bo, n_hi, n_lo) x (bo, n_lo, bb) -> (bo, n_hi, bb), batched over bo
-    part = jax.lax.dot_general(
-        table.astype(jnp.bfloat16), oh_lo, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    hi_iota = jax.lax.broadcasted_iota(jnp.int32, (bo, n_hi, bb), 1)
-    out = jnp.sum(jnp.where(hi[:, None, :] == hi_iota, part, 0.0),
-                  axis=1)                                    # (bo, bb)
-    return out.astype(jnp.int32).T                           # (bb, bo)
 
 
 def _kernel(codes_ref, idx_ref, table_ref, out_ref, *,

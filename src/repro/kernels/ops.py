@@ -49,6 +49,12 @@ def lut_lookup(codes: jax.Array, indices: jax.Array, table: jax.Array,
     per-layer fallback must route through this wrapper rather than calling
     ``lut_lookup_pallas`` directly (the bare call re-traces every layer on
     every invocation).
+
+    The table is a tracer here, so its codes cannot pick the gather: this
+    wrapper keeps the integer compare loop, exact for any int32 table.
+    The engine's per-layer artifacts decide from their host tables
+    (``engine.per_layer_gathers``) and take the two-level MXU gather
+    where it is exact.
     """
     if not use_pallas:
         return ref.lut_lookup_ref(codes, indices, table, bw_in)

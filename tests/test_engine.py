@@ -229,6 +229,62 @@ def test_round_trip_per_layer_fallback_over_budget(tmp_path):
     np.testing.assert_array_equal(np.asarray(eng2(codes)), want)
 
 
+def _gather_counts():
+    from repro import obs
+    fam = obs.registry().get("engine_per_layer_gather_total")
+    return {g: fam.labels(gather=g).value for g in ("two_level", "compare")}
+
+
+def test_per_layer_gather_follows_table_codes(tmp_path):
+    """One table code at 256 is past the exact bf16 range: that layer, and
+    only that one, keeps the integer compare loop.  The choice is counted
+    once per layer at build (and again at load), and the artifact serves
+    bit-exactly either way."""
+    widths, fan_ins, bws = STACK
+    layers = _random_stack(widths, fan_ins, bws, seed=23)
+    idx, tab, bw = layers[-1]
+    tab = tab.copy()
+    tab[0, 0] = 256
+    layers[-1] = (idx, tab, bw)
+    codes = _codes(widths[0], bws[0], 19, seed=8)
+    want = np.asarray(network_table_forward(_tables(layers), codes))
+
+    before = _gather_counts()
+    eng = engine.compile_network(layers, in_features=widths[0], fused=False)
+    assert eng.layout == "per_layer"
+    assert eng.two_level == (True, True, False)
+    after = _gather_counts()
+    assert after["two_level"] == before["two_level"] + 2
+    assert after["compare"] == before["compare"] + 1
+    np.testing.assert_array_equal(np.asarray(eng(codes)), want)
+
+    path = os.path.join(tmp_path, "per_layer.npz")
+    eng.save(path)
+    eng2 = engine.load(path)
+    assert eng2.two_level == eng.two_level
+    assert _gather_counts()["compare"] == after["compare"] + 1
+    np.testing.assert_array_equal(np.asarray(eng2(codes)), want)
+
+
+def test_mnist_shaped_stack_takes_two_level_gather_per_layer():
+    """An MNIST Table 7.1 shape (784 -> 512 x 3, fan-in 6, 2-bit codes,
+    4096-entry tables) is over the fused kernels' VMEM bound; every one of
+    its per-layer kernels takes the two-level gather, bit-exactly."""
+    widths, fan_ins, bws = (784, 512, 512, 512), (6, 6, 6), (2, 2, 2)
+    layers = _random_stack(widths, fan_ins, bws, seed=5)
+    codes = _codes(widths[0], bws[0], 9, seed=3)
+    before = _gather_counts()
+    eng = engine.compile_network(layers, in_features=widths[0])
+    assert eng.layout == "per_layer"
+    assert eng.plan.reason == "kernel_exceeds_vmem_limit"
+    assert eng.two_level == (True, True, True)
+    after = _gather_counts()
+    assert after["two_level"] == before["two_level"] + 3
+    assert after["compare"] == before["compare"]
+    want = np.asarray(network_table_forward(_tables(layers), codes))
+    np.testing.assert_array_equal(np.asarray(eng(codes)), want)
+
+
 def test_load_rejects_foreign_npz(tmp_path):
     from repro.checkpoint import save_arrays
 

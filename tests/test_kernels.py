@@ -7,7 +7,8 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.lut_lookup import lut_lookup_pallas
+from repro.kernels.lut_lookup import (lut_lookup_pallas, two_level_exact,
+                                      two_level_table)
 from repro.kernels.masked_matmul import masked_matmul_pallas
 from repro.kernels.ops import interpret_mode
 
@@ -23,15 +24,21 @@ def _indices(n_out, n_in, fan_in, seed=0):
 # lut_lookup
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("batch,n_in,n_out,fan_in,bw", [
-    (4, 8, 8, 2, 1),
-    (17, 12, 9, 3, 2),      # non-divisible batch/neurons
-    (64, 32, 16, 2, 3),
-    (256, 64, 64, 4, 2),    # multi-block batch
-    (33, 16, 200, 3, 1),    # multi-block neurons
-    (8, 24, 5, 6, 2),       # 12-bit tables, multiple e-chunks
+@pytest.mark.parametrize("gather", ["compare", "two_level"])
+@pytest.mark.parametrize("batch,n_in,n_out,fan_in,bw,block_b,block_o", [
+    (4, 8, 8, 2, 1, 16, 32),
+    (17, 12, 9, 3, 2, 16, 32),      # non-divisible batch/neurons
+    (64, 32, 16, 2, 3, 16, 32),
+    (256, 64, 64, 4, 2, 16, 32),    # multi-block batch
+    (33, 16, 200, 3, 1, 16, 32),    # multi-block neurons
+    (8, 24, 5, 6, 2, 16, 32),       # 12-bit tables, multiple e-chunks
+    # chip tile sizes: 1024- and 4096-entry tables, 200 neurons (not a
+    # multiple of block_o) and 130 rows (not a multiple of block_b)
+    (130, 40, 200, 5, 2, 128, 128),
+    (130, 40, 200, 6, 2, 128, 128),
 ])
-def test_lut_lookup_matches_ref(batch, n_in, n_out, fan_in, bw):
+def test_lut_lookup_matches_ref(batch, n_in, n_out, fan_in, bw, block_b,
+                                block_o, gather):
     key = jax.random.PRNGKey(batch + n_out)
     codes = jax.random.randint(key, (batch, n_in), 0, 2 ** bw,
                                dtype=jnp.int32)
@@ -39,8 +46,11 @@ def test_lut_lookup_matches_ref(batch, n_in, n_out, fan_in, bw):
     table = jax.random.randint(jax.random.PRNGKey(1), (n_out,
                                2 ** (fan_in * bw)), 0, 2 ** bw,
                                dtype=jnp.int32)
-    got = lut_lookup_pallas(codes, idx, table, bw, block_b=16, block_o=32,
-                            e_chunk=64, interpret=interpret_mode())
+    assert two_level_exact(table, bw)
+    kernel_table = table if gather == "compare" else two_level_table(table)
+    got = lut_lookup_pallas(codes, idx, kernel_table, bw, block_b=block_b,
+                            block_o=block_o, e_chunk=64,
+                            interpret=interpret_mode())
     want = ref.lut_lookup_ref(codes, idx, table, bw)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 

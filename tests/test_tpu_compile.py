@@ -144,16 +144,31 @@ def _mnist_stack(hidden, bw, fan_in, seed=0):
 
 def test_mnist_stacks_refused_by_fused_plan_and_per_layer_compiles(one_chip):
     """No MNIST Table 7.1 stack fits the fused kernel's VMEM bound, so the
-    plan refuses each one with a reason, and the per-layer kernel they
-    take instead compiles at the largest stack's widest layer."""
+    plan refuses each one with a reason.  The per-layer kernels they take
+    instead compile: the two-level gather at the mnist-512x3 shapes
+    (784 -> 512 -> 512, 4096 entries, block_b 128) through the engine's
+    jitted forward, and the compare loop at the largest stack's widest
+    layer."""
+    from repro.engine.engine import _per_layer_forward
+
     plans = {hidden: fused_plan(_mnist_stack(hidden, bw, fi))
              for hidden, bw, fi in mnist.TABLE_7_1}
     assert all(p.reason == "kernel_exceeds_vmem_limit" and not p.fused
                for p in plans.values())
+    codes = np.zeros((256, mnist.IN_FEATURES), np.int32)
+
+    stack = _mnist_stack((512, 512), 2, 6)
+    assert all(t.shape[1] == 4096 for _, t, _ in stack)
+    fn = functools.partial(_per_layer_forward, bws=(2, 2),
+                           two_level=(True, True), block_b=128,
+                           interpret=False)
+    hlo = _compile(fn, (codes, tuple((i, t) for i, t, _ in stack)),
+                   one_chip)
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 2
+
     hidden, bw, fi = max(mnist.TABLE_7_1,
                          key=lambda r: plans[r[0]].slab_bytes)
     idx, tab, _ = _mnist_stack(hidden[:1], bw, fi)[0]
-    codes = np.zeros((256, mnist.IN_FEATURES), np.int32)
     fn = functools.partial(lut_lookup_pallas, bw_in=bw, block_b=128)
     assert "tpu_custom_call" in _compile(fn, (codes, idx, tab), one_chip)
 
