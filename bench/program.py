@@ -37,12 +37,14 @@ def logicnet_cfg(cfg: dict):
         cfg["in_features"], cfg["n_classes"], hidden=tuple(cfg["hidden"]),
         fan_in=cfg["fan_in"], bw=cfg["bw"], final_dense=cfg["final_dense"],
         fan_in_fc=cfg.get("fan_in_fc"), bw_fc=cfg["bw_fc"],
-        max_val=cfg["max_val"])
+        max_val=cfg["max_val"],
+        skips=tuple(tuple(p) for p in cfg.get("skips", ())))
 
 
 def program_model(weights: list[dict]) -> list[dict]:
     """The bench's weights in the program's layer structure (dense
-    masked weight matrices, batch-norm parameters and running stats)."""
+    masked weight matrices over each layer's whole input bus, skips
+    included, batch-norm parameters and running stats)."""
     model = []
     for lay in weights:
         n_in, n_out = lay["n_in"], lay["n_out"]
