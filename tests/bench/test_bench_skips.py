@@ -7,6 +7,7 @@ import hashlib
 import json
 import os
 import sys
+import traceback
 
 import numpy as np
 import pytest
@@ -160,16 +161,102 @@ def test_program_is_handed_the_skips(monkeypatch):
     assert seen == [((0, 2), (1, 2))]
 
 
-def test_a_skip_stack_is_not_served_as_a_plain_stack():
-    """The program has no table path for skips yet: the run stops there,
-    and does not serve the stack without them."""
+def _run_skip():
     import jax
     program.import_program()
     cell = {"name": "tiny-skip.scan", "chips": 1}
     mix = {"kind": "closed_loop_scan", "rows_per_call": 64,
            "distinct_batches": 1, "block_b": 128}
-    with pytest.raises(NotImplementedError):
-        run.run_cell(cell, SKIP, mix, 5, 0.5, False, jax.devices())
+    return run.run_cell(cell, SKIP, mix, 5, 0.5, False, jax.devices())
+
+
+def _forward(weights, codes, max_val, drop_skips):
+    """The reference's codes of the stack; with ``drop_skips`` every
+    skip segment of every bus is code 0, as a program that serves the
+    stack without its skips would read it."""
+    acts = [np.asarray(codes, np.int32)]
+    for layer in weights:
+        prev, *skips = layer["sources"]
+        bus = [acts[prev]] + [np.zeros_like(acts[s]) if drop_skips
+                              else acts[s] for s in skips]
+        acts.append(logicnet_ref.layer_forward(
+            layer, np.concatenate(bus, axis=1), max_val))
+    return acts[-1]
+
+
+def test_a_skip_stack_is_not_served_as_a_plain_stack():
+    """A skip configuration is never served as a plain stack: either the
+    program's table generation refuses it, or the run serves it and
+    agrees with the skip-aware reference."""
+    try:
+        res = _run_skip()
+    except NotImplementedError as exc:
+        frames = traceback.extract_tb(exc.__traceback__)
+        assert any(f.name == "generate_tables" and os.sep + "repro" + os.sep
+                   in f.filename for f in frames), frames
+        return
+    assert res["correct"] is True
+    assert res["checks"]["codes_compared"]["value"] >= 1
+    assert res["checks"]["mismatch_share"]["value"] == 0
+
+
+class _StandIn:
+    """A served artifact that answers with the reference's codes."""
+
+    layout = "stand-in"
+
+    def __init__(self, cfg, weights, drop_skips):
+        self.cfg, self.weights, self.drop_skips = cfg, weights, drop_skips
+
+    def __call__(self, codes):
+        return _forward(self.weights, np.asarray(codes),
+                        self.cfg["max_val"], self.drop_skips)
+
+    def jit_cache_size(self):
+        return 0
+
+
+@pytest.mark.parametrize("drop_skips, correct", [(True, False),
+                                                 (False, True)])
+def test_the_harness_fails_a_stack_served_without_its_skips(
+        drop_skips, correct, monkeypatch):
+    """The program's layers from the weights to the served codes, its
+    table generation (``program.make_tables``) and its compiled artifact
+    (``program.compile_net``), are replaced by a stand-in that answers
+    with the reference's codes; with ``drop_skips`` each skip segment of
+    each bus is code 0.  The scan kind's loop, its sample and the
+    comparison are the harness's own: it reads a stack served without
+    its skips as incorrect, and one served with them as correct."""
+    monkeypatch.setattr(program, "make_tables",
+                        lambda cfg, weights: weights)
+    monkeypatch.setattr(program, "compile_net",
+                        lambda cfg, weights, block_b:
+                        _StandIn(cfg, weights, drop_skips))
+    res = _run_skip()
+    mismatch = res["checks"]["mismatch_share"]
+    assert res["correct"] is correct
+    assert res["checks"]["codes_compared"]["value"] >= 1
+    if drop_skips:
+        assert mismatch["value"] > mismatch["limit"]
+    else:
+        assert mismatch["value"] == 0
+
+
+def test_dropped_skips_change_far_more_codes_than_the_limit():
+    """At the published widths of the next configuration, mnist-512x3
+    with ``skips`` [[0, 2], [1, 2]]: zeroing the skip segments of the
+    last sparse layer's 1808-wide bus changes far more than the limit's
+    share of the output codes, so the limit catches a dropped skip."""
+    cfg = dict(_cfg("mnist-512x3"), skips=[[0, 2], [1, 2]])
+    w = logicnet_ref.make_weights(cfg, cfg["model_seed"])
+    x = _codes(cfg, 256, 1)
+    want = logicnet_ref.forward(w, x, cfg["max_val"])
+    np.testing.assert_array_equal(_forward(w, x, cfg["max_val"], False), want)
+    share = float((_forward(w, x, cfg["max_val"], True) != want).mean())
+    limit = cfg["limits"]["mismatch_share"]
+    print(f"dropped skips change {share} of the codes "
+          f"({share / limit:.0f} x the limit {limit})")
+    assert share > 100 * limit
 
 
 # -- (f) skips cost no lookups and move no bytes ------------------------------
