@@ -96,6 +96,11 @@ _M_PER_LAYER_GATHER = obs.registry().counter(
     "engine_per_layer_gather_total",
     "layers of per-layer artifacts by the table gather their kernel takes",
     labels=("gather",))
+_M_CALLS = obs.registry().counter(
+    "engine_calls_total",
+    "CompiledLUTNet calls by where the batch came from: host (put flat) "
+    "or device (served as it is)",
+    labels=("input",))
 _M_SLAB_BUILD = obs.registry().histogram(
     "engine_slab_build_seconds",
     "host-side slab construction time per compile_network build")
@@ -167,6 +172,24 @@ _FORWARDS = {"uniform": _uniform_forward, "mixed": _mixed_forward,
              "per_layer": _per_layer_forward, "reference": _reference_forward}
 
 
+# ---------------------------------------------------------------------------
+# The host-batch entry.  A TPU lays a narrow int32 matrix out column-major
+# by default (``s32[8192,784]{0,1}``), so putting a host batch as it is
+# makes PJRT transpose it on the host and the forward copy it back to
+# row-major on the device.  A 1-D array's device layout is the host's byte
+# order: the batch crosses flat and is unflattened on the device.  The
+# result keeps its default layout: pinned row-major, a result narrower
+# than 128 lanes is padded to them and fetched slower (jsc-a's 64-wide
+# codes, on a v5e).  The layout's forward stays a jitted function of its
+# own inside the entry, so its kernels keep their names.
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnames=("layout", "static", "n_in"))
+def _host_forward(flat, arrays, *, layout, static, n_in):
+    return _FORWARDS[layout](flat.reshape(-1, n_in), *arrays, **dict(static))
+
+
 @dataclasses.dataclass(frozen=True)
 class CompiledLUTNet:
     """An ahead-of-time compiled LUT network, ready to serve.
@@ -213,46 +236,47 @@ class CompiledLUTNet:
         sliced back, so any batch in (0, block_b] reuses one trace — a
         steady-state serving loop performs zero re-traces after warmup.
 
+        A batch already on the device (a ``jax.Array``, as the serving
+        tier hands over) goes straight to the layout's forward.  A host
+        batch is put flat, in its own byte order, and the host-batch
+        entry unflattens it on the device (see ``_host_forward``).
+
         Two spans time its host work: ``engine.h2d``, the put of the
-        codes (the host lays them out and starts the copy), and
-        ``engine.dispatch``, until the forward call returns.  Nothing
-        waits for the device: the caller keeps async dispatch.
+        codes, and ``engine.dispatch``, until the forward call returns.
+        Nothing waits for the device: the caller keeps async dispatch.
         """
+        host = not isinstance(codes, jax.Array)
+        _M_CALLS.labels(input="host" if host else "device").inc()
         with obs.span("engine.h2d"):
-            # the tier hands over its batch already on the device, where
-            # jnp.asarray would cost tens of µs of host time for nothing
-            if not (isinstance(codes, jax.Array)
-                    and codes.dtype == jnp.int32):
+            if host:
+                codes = np.ascontiguousarray(codes, dtype=np.int32)
+            elif codes.dtype != jnp.int32:
                 codes = jnp.asarray(codes, dtype=jnp.int32)
-        if codes.ndim != 2 or codes.shape[1] != self.n_in:
-            raise ValueError(
-                f"expected (batch, {self.n_in}) codes, got {codes.shape}")
-        batch = codes.shape[0]
-        if batch == 0:
-            return jnp.zeros((0, self.n_out), dtype=jnp.int32)
+            if codes.ndim != 2 or codes.shape[1] != self.n_in:
+                raise ValueError(
+                    f"expected (batch, {self.n_in}) codes, got {codes.shape}")
+            batch = codes.shape[0]
+            if batch == 0:
+                return jnp.zeros((0, self.n_out), dtype=jnp.int32)
+            if host:
+                codes = jax.device_put(codes.reshape(-1))
         with obs.span("engine.dispatch"):
-            padded = -(-batch // self.block_b) * self.block_b
-            if padded != batch:
-                codes = jnp.concatenate(
-                    [codes, jnp.zeros((padded - batch, self.n_in),
-                                      dtype=codes.dtype)], axis=0)
-            out = self._apply(codes)
-            return out[:batch] if padded != batch else out
+            pad = -batch % self.block_b
+            if pad:
+                codes = jnp.pad(codes, (0, pad * self.n_in) if host
+                                else ((0, pad), (0, 0)))
+            static, arrays = self._parts()
+            if host:
+                out = _host_forward(codes, arrays, layout=self.layout,
+                                    static=tuple(sorted(static.items())),
+                                    n_in=self.n_in)
+            else:
+                out = _FORWARDS[self.layout](codes, *arrays, **static)
+            return out[:batch] if pad else out
 
-    def _apply(self, codes: jax.Array) -> jax.Array:
-        fn, arrays = self.forward_parts()
-        return fn(codes, *arrays)
-
-    def forward_parts(self):
-        """``(fn, arrays)`` such that ``fn(codes, *arrays)`` is this
-        artifact's jitted forward on a padded batch.
-
-        ``fn`` binds the static half (layout metadata, ``block_b`` and
-        the kernel backend); ``arrays`` are the slabs.  A caller that
-        places the slabs itself — the sharded serving tier replicates
-        them over its mesh — passes them as arguments instead of letting
-        a trace capture them as constants on one device.
-        """
+    def _parts(self):
+        """``(static, arrays)``: the static keywords of the layout's
+        jitted forward in ``_FORWARDS``, and the slabs it takes."""
         if self.layout == "mixed":
             s = self.slabs
             arrays = (s.idx_slab, s.shift_slab, s.width_slab, s.table_slabs)
@@ -269,17 +293,37 @@ class CompiledLUTNet:
             static["two_level"] = self.two_level
         if self.layout != "reference":
             static.update(block_b=self.block_b, interpret=interpret_mode())
+        return static, arrays
+
+    def forward_parts(self):
+        """``(fn, arrays)`` such that ``fn(codes, *arrays)`` is this
+        artifact's jitted forward on a padded batch.
+
+        ``fn`` binds the static half (layout metadata, ``block_b`` and
+        the kernel backend); ``arrays`` are the slabs.  A caller that
+        places the slabs itself — the sharded serving tier replicates
+        them over its mesh — passes them as arguments instead of letting
+        a trace capture them as constants on one device.
+        """
+        static, arrays = self._parts()
         return functools.partial(_FORWARDS[self.layout], **static), arrays
 
-    def jit_cache_size(self) -> int:
-        """Trace count of this artifact's (shared) jitted forward.
+    def jit_cache_size(self, host: bool = True) -> int:
+        """Trace count of this artifact's (shared) jitted forward and,
+        unless ``host`` is False, of the host-batch entry.
 
-        The forwards are process-wide per layout, so treat this as a
-        monotonic counter: a steady-state serving loop must not grow it
-        (the bench's ``retraces_after_warmup`` and the regression tests
-        take before/after deltas).
+        Both are process-wide, so treat this as a monotonic counter: a
+        steady-state serving loop must not grow it (the bench's
+        ``retraces_after_warmup`` and the regression tests take
+        before/after deltas).  A caller that only hands over device
+        batches, as the serving tier does, passes ``host=False``, so
+        that host calls elsewhere in the process are not counted as its
+        own.
         """
-        return _FORWARDS[self.layout]._cache_size()
+        n = _FORWARDS[self.layout]._cache_size()
+        if host:
+            n += _host_forward._cache_size()
+        return n
 
     def vmem_breakdown(self) -> dict:
         """Per-slab VMEM bytes of the chosen layout (serving diagnostics)."""

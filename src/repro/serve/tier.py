@@ -297,7 +297,8 @@ class ServingTier:
         return out, padded_rows, h2d.start, d2h.end
 
     def _trace_count(self) -> int:
-        n = self._net.jit_cache_size()
+        # the tier hands the artifact device batches only
+        n = self._net.jit_cache_size(host=False)
         if self._sharded_jit is not None:
             n += self._sharded_jit._cache_size()
         return n

@@ -78,16 +78,85 @@ def test_batch_edges_and_input_validation():
     widths, fan_ins, bws = STACK
     layers = _random_stack(widths, fan_ins, bws, seed=13)
     eng = engine.compile_network(layers, in_features=widths[0], block_b=8)
-    empty = eng(jnp.zeros((0, widths[0]), jnp.int32))
-    assert empty.shape == (0, widths[-1]) and empty.dtype == jnp.int32
-    with pytest.raises(ValueError, match="expected"):
-        eng(jnp.zeros((4, widths[0] + 1), jnp.int32))
+    for zeros in (jnp.zeros, np.zeros):          # device and host batches
+        empty = eng(zeros((0, widths[0]), np.int32))
+        assert empty.shape == (0, widths[-1]) and empty.dtype == jnp.int32
+        with pytest.raises(ValueError, match="expected"):
+            eng(zeros((4, widths[0] + 1), np.int32))
     # ragged batches (pad-and-slice) match the unpadded reference
     codes = _codes(widths[0], bws[0], 11, seed=2)
     want = np.asarray(network_table_forward(_tables(layers), codes))
     np.testing.assert_array_equal(np.asarray(eng(codes)), want)
     # numpy input is accepted
     np.testing.assert_array_equal(np.asarray(eng(np.asarray(codes))), want)
+
+
+def _calls():
+    from repro import obs
+    fam = obs.registry().get("engine_calls_total")
+    return {i: fam.labels(input=i).value for i in ("host", "device")}
+
+
+@pytest.mark.parametrize("batch", [1, 7, 9])     # block_b 8: -1, +1
+@pytest.mark.parametrize("kwargs,layout", [
+    ({}, "uniform"),
+    ({"optimize_level": 3}, "mixed"),
+    ({"fused": False}, "per_layer"),
+    ({"use_pallas": False}, "reference"),
+])
+def test_host_and_device_batches_agree(kwargs, layout, batch):
+    """A host batch (put flat, through the host-batch entry) and the same
+    batch already on the device give identical codes, bit-exact with the
+    reference, for ragged batches; each call is counted by where its
+    batch came from, and once both paths are warm, other batches of the
+    same ``block_b`` bucket add no trace."""
+    widths, fan_ins, bws = STACK
+    layers = _random_stack(widths, fan_ins, bws, seed=19)
+    eng = engine.compile_network(layers, in_features=widths[0], block_b=8,
+                                 **kwargs)
+    assert eng.layout == layout
+    padded = -(-batch // 8) * 8
+    host = np.random.default_rng(batch).integers(
+        0, 2 ** bws[0], (padded, widths[0]), dtype=np.int32)
+    want = np.asarray(network_table_forward(_tables(layers), host))
+
+    calls0 = _calls()
+    got_host = np.asarray(eng(host[:batch]))
+    got_device = np.asarray(eng(jnp.asarray(host[:batch])))
+    np.testing.assert_array_equal(got_host, want[:batch])
+    np.testing.assert_array_equal(got_device, want[:batch])
+    calls = _calls()
+    assert calls["host"] == calls0["host"] + 1
+    assert calls["device"] == calls0["device"] + 1
+
+    traces0 = eng.jit_cache_size()
+    for n in (padded - 7, batch, padded):          # the same bucket
+        np.testing.assert_array_equal(np.asarray(eng(host[:n])), want[:n])
+        np.testing.assert_array_equal(
+            np.asarray(eng(jnp.asarray(host[:n]))), want[:n])
+    assert eng.jit_cache_size() == traces0, "a warm bucket re-traced"
+
+
+@pytest.mark.parametrize("form", ["non_contiguous", "fortran", "int8",
+                                  "int64", "list"])
+def test_host_batch_forms(form):
+    """Host batches that are not C-contiguous int32 are served as their
+    int32 codes: strided views, column-major arrays, narrower and wider
+    integer types, and nested lists."""
+    widths, fan_ins, bws = STACK
+    layers = _random_stack(widths, fan_ins, bws, seed=37)
+    eng = engine.compile_network(layers, optimize_level=3,
+                                 in_features=widths[0], block_b=8)
+    wide = np.random.default_rng(5).integers(
+        0, 2 ** bws[0], (11, 2 * widths[0]), dtype=np.int32)
+    codes = np.ascontiguousarray(wide[:, ::2])
+    want = np.asarray(network_table_forward(_tables(layers), codes))
+    batch = {"non_contiguous": wide[:, ::2],
+             "fortran": np.asfortranarray(codes),
+             "int8": codes.astype(np.int8),
+             "int64": codes.astype(np.int64),
+             "list": codes.tolist()}[form]
+    np.testing.assert_array_equal(np.asarray(eng(batch)), want)
 
 
 @pytest.mark.parametrize("kwargs,layout", [

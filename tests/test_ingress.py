@@ -240,8 +240,8 @@ class _SlowNet:
         self.n_in, self.n_out = inner.n_in, inner.n_out
         self.block_b = inner.block_b
 
-    def jit_cache_size(self):
-        return self._inner.jit_cache_size()
+    def jit_cache_size(self, host=True):
+        return self._inner.jit_cache_size(host)
 
     def __call__(self, codes):
         time.sleep(self._delay)

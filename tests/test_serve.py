@@ -132,8 +132,8 @@ def _slow_net(net, delay_s):
             time.sleep(delay_s)
             return net(codes)
 
-        def jit_cache_size(self):
-            return net.jit_cache_size()
+        def jit_cache_size(self, host=True):
+            return net.jit_cache_size(host)
 
     return Slow()
 

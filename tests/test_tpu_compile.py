@@ -14,6 +14,7 @@ doing it at import would break a multi-worker test run.
 
 import functools
 import os
+import re
 
 import jax
 import numpy as np
@@ -194,3 +195,44 @@ def test_sharded_tier_forward_compiles_for_four_chips(topo, model_a,
                     ).compile().as_text()
     assert "tpu_custom_call" in hlo
     assert "all-gather" not in hlo and "all-reduce" not in hlo
+
+
+def _mnist_512x3():
+    stack = _mnist_stack((512, 512, 512), 2, 6)
+    return stack, mnist.IN_FEATURES, {}
+
+
+def _model_a_level3(model_a):
+    cfg, tables, _ = model_a
+    return tables, cfg.in_features, {"optimize_level": 3}
+
+
+@pytest.mark.parametrize("case,rows,layout,kernel", [
+    ("mnist-512x3", 8192, "per_layer", "_per_layer_forward"),
+    ("model-a-level3", 65536, "mixed", "_mixed_forward"),
+])
+def test_host_batch_entry_takes_codes_flat(
+        one_chip, model_a, monkeypatch, case, rows, layout, kernel):
+    """A host batch at the scan shapes crosses in the host's byte order:
+    the entry's codes parameter is 1-D (a narrow int32 matrix would be
+    column-major, ``{0,1}``, and make the put transpose on the host), and
+    the kernel keeps the name of the layout's jitted forward, which the
+    benchmark's roofline readers look for."""
+    from repro import engine
+
+    monkeypatch.setattr(engine.engine, "interpret_mode", lambda: False)
+    layers, n_in, kwargs = (_mnist_512x3() if case == "mnist-512x3"
+                            else _model_a_level3(model_a))
+    net = engine.compile_network(layers, in_features=n_in, block_b=128,
+                                 **kwargs)
+    assert net.layout == layout
+    static, arrays = net._parts()
+    flat = jax.ShapeDtypeStruct((rows * n_in,), np.int32, sharding=one_chip)
+    hlo = engine.engine._host_forward.lower(
+        flat, _shapes(arrays, one_chip), layout=layout,
+        static=tuple(sorted(static.items())), n_in=n_in).compile().as_text()
+    params = re.search(r"entry_computation_layout=\{\((.*?)\)->", hlo)[1]
+    assert params.startswith(f"s32[{rows * n_in}]{{0:")
+    calls = re.findall(r"%(\w+)\.\d+ = \S+ custom-call\(.*"
+                       r"custom_call_target=\"tpu_custom_call\"", hlo)
+    assert calls and set(calls) == {kernel}
